@@ -41,6 +41,7 @@ import numpy as np
 from repro import api
 from repro.core import adapt as adapt_mod
 from repro.core.backbones import cnn_backbone
+from repro.launch.mesh import make_mesh
 from repro.models import edge_cnn as E
 
 DEFAULT_OUT = "BENCH_adaptation.json"
@@ -219,7 +220,7 @@ def run(
 
     # -- section 4: bucketed heterogeneous fleet on a local data mesh ------
     if jax.device_count() > 1:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
         msession = api.TinyTrainSession(bb, max_way=max_way, seed=seed)
         msession.adapt_many(mixes[0], api.RPI_ZERO, iters=fleet_iters,
                             mesh=mesh)  # warm-up

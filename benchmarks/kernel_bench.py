@@ -80,10 +80,12 @@ def main(quick: bool = True) -> List[str]:
     v = jax.random.normal(jax.random.PRNGKey(9), (b, cap, hkv, hd))
     perm = jax.random.permutation(jax.random.PRNGKey(10), b * mp)
     table = perm.reshape(b, mp).astype(jnp.int32)
-    kp = jnp.zeros((b * mp, ps, hkv, hd)).at[table.reshape(-1)].set(
-        k.reshape(b * mp, ps, hkv, hd))
-    vp = jnp.zeros((b * mp, ps, hkv, hd)).at[table.reshape(-1)].set(
-        v.reshape(b * mp, ps, hkv, hd))
+    # head-major page arenas (n_pages, Hkv, page_size, D), as
+    # PG.store_init lays them out
+    kp = jnp.zeros((b * mp, hkv, ps, hd)).at[table.reshape(-1)].set(
+        k.reshape(b * mp, ps, hkv, hd).swapaxes(1, 2))
+    vp = jnp.zeros((b * mp, hkv, ps, hd)).at[table.reshape(-1)].set(
+        v.reshape(b * mp, ps, hkv, hd).swapaxes(1, 2))
     kv_len = jnp.asarray([cap - 5, cap // 2] * (b // 2), jnp.int32)
     for sq, tag in ((1, "decode"), (8, "prefill8")):
         qo = kv_len - sq
@@ -106,7 +108,8 @@ def main(quick: bool = True) -> List[str]:
     # int8 page store: gather-only (fp pages) vs gather + rowwise dequant
     import dataclasses as _dc
     spec_i8 = _dc.replace(spec, int8=True)
-    q8, sc = rowwise_quant(kp, 2)
+    q8, sc = rowwise_quant(kp.swapaxes(1, 2), 2)  # one scale per token row
+    q8 = q8.swapaxes(1, 2)
     read_fp = jax.jit(lambda t: PG.read_rows({"pages": kp}, t, spec,
                                              jnp.float32))
     read_i8 = jax.jit(lambda t: PG.read_rows(

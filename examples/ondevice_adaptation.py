@@ -79,7 +79,9 @@ print(f"heterogeneous fleet: {len(het)} users, {len(shapes)} episode "
 import jax
 
 if jax.device_count() > 1:
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((jax.device_count(),), ("data",))
     t0 = time.perf_counter()
     sharded = session.adapt_many(het_tasks, profile, iters=20, mesh=mesh)
     dt = time.perf_counter() - t0

@@ -48,15 +48,30 @@ def fisher_probe(
     )
     taps = backbone.make_taps(batch_pad)
 
-    def f(t):
-        return loss_fn(params, batch, taps=t)
-
     t0 = time.perf_counter()
-    g = jax.grad(f)(taps)
-    g = jax.tree_util.tree_map(lambda x: np.asarray(x), g)
-    potentials, chans = backbone.fisher_from_grads(g, n_samples)
+    if backbone.fisher_reduce is not None:
+        chans = jax.device_get(probe_fn(backbone, loss_fn)(
+            params, batch, taps, jnp.float32(n_samples)))
+        potentials = potentials_from_chans(backbone.unit_costs, chans)
+    else:
+        g = jax.grad(lambda t: loss_fn(params, batch, taps=t))(taps)
+        g = jax.tree_util.tree_map(lambda x: np.asarray(x), g)
+        potentials, chans = backbone.fisher_from_grads(g, n_samples)
     dt = time.perf_counter() - t0
     return potentials, chans, dt
+
+
+def probe_fn(backbone: Backbone, loss_fn: Callable[..., jax.Array]):
+    """Jitted probe ``pf(params, batch, taps, n) -> {(layer, kind): Δ_o}``:
+    tap gradients and the backbone's device-side Eq. 2 reduction in one
+    program, so only the O(L·C) scores reach the host (on TPU the LM
+    reduction is the Pallas fisher kernel)."""
+
+    def pf(params, batch, taps, n):
+        g = jax.grad(lambda t: loss_fn(params, batch, taps=t))(taps)
+        return backbone.fisher_reduce(g, n)
+
+    return jax.jit(pf)
 
 
 def fisher_from_activations(a: jax.Array, g: jax.Array,

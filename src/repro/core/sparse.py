@@ -284,13 +284,7 @@ class EpisodeStepCache:
         """Total compiled fleet-scan programs (every (bucket shape, task
         count, policy structure, iters, mode) variant XLA actually built —
         the quantity the O(#buckets x #structures) contract bounds)."""
-        total = 0
-        for f in self._vscans.values():
-            try:
-                total += f._cache_size()
-            except Exception:  # jit cache introspection is version-coupled
-                total += 1
-        return total
+        return sum(f._cache_size() for f in self._vscans.values())
 
     @staticmethod
     def chan_idx_arrays(policy: SparseUpdatePolicy):
@@ -433,16 +427,15 @@ class EpisodeStepCache:
                     # (FleetShardingRules replicates too) — run locally
                     fleet = local
                 else:
-                    from jax.experimental.shard_map import shard_map
                     from jax.sharding import PartitionSpec as P
 
                     ts = P(dp if len(dp) > 1 else dp[0])  # task-axis prefix
                     # callers pad the stacked task axis to a multiple of
                     # the data size (FleetShardingRules.padded_count), so
                     # every shard sees an equal local slice
-                    fleet = shard_map(
+                    fleet = jax.shard_map(
                         local, mesh=mesh, in_specs=(P(), ts, ts, ts),
-                        out_specs=ts, check_rep=False)
+                        out_specs=ts, check_vma=False)
 
             self._vscans[key] = jax.jit(fleet)
         return self._vscans[key]

@@ -10,6 +10,13 @@ causal FLOPs vs a masked dense computation.
 Grid: (B, Hq, Sq/Bq, Sk/Bk).  GQA: the kv block index maps query head
 h -> kv head h // (Hq/Hkv) in the BlockSpec index map (no HBM repeat).
 
+Layout: every block is one head's ``(rows, D)`` tile of a head-major
+``(B, H, S, D)`` operand, so the last two block dimensions are (rows, D)
+as Mosaic's tiling requires.  The public wrappers take the model's
+``(B, S, H, D)`` and transpose q/k/v; the paged kernel reads the page
+arena in place, because ``serving/paging.py`` stores pages head-major
+``(n_pages, Hkv, page_size, D)``.
+
 Two entry modes share the kernel body:
 
 - aligned prefill (``q_offset=None``): queries and keys index the same
@@ -59,9 +66,9 @@ def _flash_kernel(
 
     @pl.when(relevant)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
@@ -84,7 +91,7 @@ def _flash_kernel(
 
     @pl.when(ki == n_kv_blocks - 1)
     def _out():
-        o_ref[0, :, 0, :] = (
+        o_ref[0, 0] = (
             acc[...] / jnp.maximum(l_acc[...], 1e-30)
         ).astype(o_ref.dtype)
 
@@ -124,9 +131,9 @@ def _flash_cached_kernel(
 
     @pl.when(relevant)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
@@ -149,7 +156,7 @@ def _flash_cached_kernel(
 
     @pl.when(ki == n_kv_blocks - 1)
     def _out():
-        o_ref[0, :, 0, :] = (
+        o_ref[0, 0] = (
             acc[...] / jnp.maximum(l_acc[...], 1e-30)
         ).astype(o_ref.dtype)
 
@@ -188,9 +195,9 @@ def _flash_paged_kernel(
 
     @pl.when(relevant)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)  # (ps, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, ps)
@@ -211,14 +218,14 @@ def _flash_paged_kernel(
 
     @pl.when(ki == n_kv_blocks - 1)
     def _out():
-        o_ref[0, :, 0, :] = (
+        o_ref[0, 0] = (
             acc[...] / jnp.maximum(l_acc[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
 def flash_attention_paged_pallas(
     q: jax.Array,        # (B, Sq, Hq, D)
-    k_pages: jax.Array,  # (n_pages, page_size, Hkv, D) flat page arena
+    k_pages: jax.Array,  # (n_pages, Hkv, page_size, D) head-major arena
     v_pages: jax.Array,
     page_table: jax.Array,  # (B, max_pages) int32; -1 = unmapped
     *,
@@ -235,7 +242,7 @@ def flash_attention_paged_pallas(
     physical page, so the kernel reads the arena in place.
     """
     b, sq, hq, d = q.shape
-    n_pages, ps, hkv, _ = k_pages.shape
+    n_pages, hkv, ps, _ = k_pages.shape
     group = hq // hkv
     mp = page_table.shape[1]
     bq = min(block_q, sq)
@@ -245,25 +252,25 @@ def flash_attention_paged_pallas(
         num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, d),
-                         lambda bi, h, qi, ki, pt, qo, kl: (bi, qi, h, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+            pl.BlockSpec((1, 1, bq, d),
+                         lambda bi, h, qi, ki, pt, qo, kl: (bi, h, qi, 0)),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda bi, h, qi, ki, pt, qo, kl:
-                         (jnp.maximum(pt[bi, ki], 0), 0, h // group, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+                         (jnp.maximum(pt[bi, ki], 0), h // group, 0, 0)),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda bi, h, qi, ki, pt, qo, kl:
-                         (jnp.maximum(pt[bi, ki], 0), 0, h // group, 0)),
+                         (jnp.maximum(pt[bi, ki], 0), h // group, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, d),
+        out_specs=pl.BlockSpec((1, 1, bq, d),
                                lambda bi, h, qi, ki, pt, qo, kl:
-                               (bi, qi, h, 0)),
+                               (bi, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _flash_paged_kernel,
             scale=1.0 / math.sqrt(d),
@@ -271,10 +278,16 @@ def flash_attention_paged_pallas(
             bq=bq, ps=ps, causal=causal,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32),
-      kv_len.astype(jnp.int32), q, k_pages, v_pages)
+      kv_len.astype(jnp.int32), _heads_major(q), k_pages, v_pages)
+    return _heads_major(out)
+
+
+def _heads_major(x: jax.Array) -> jax.Array:
+    """(B, S, H, D) <-> (B, H, S, D)."""
+    return jnp.swapaxes(x, 1, 2)
 
 
 def flash_attention_pallas(
@@ -297,57 +310,49 @@ def flash_attention_pallas(
     bk = min(block_k, sk)
     assert sq % bq == 0 and sk % bk == 0
     grid = (b, hq, sq // bq, sk // bk)
-
-    if q_offset is None and kv_len is None:
-        return pl.pallas_call(
-            functools.partial(
-                _flash_kernel,
-                scale=1.0 / math.sqrt(d),
-                n_kv_blocks=sk // bk,
-                bq=bq, bk=bk, causal=causal, window=window,
-            ),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bq, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-                pl.BlockSpec((1, bk, 1, d), lambda bi, h, qi, ki: (bi, ki, h // group, 0)),
-                pl.BlockSpec((1, bk, 1, d), lambda bi, h, qi, ki: (bi, ki, h // group, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, bq, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, sq, hq, d), q.dtype),
-            scratch_shapes=[
-                pltpu.VMEM((bq, d), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-            ],
-            interpret=interpret,
-        )(q, k, v)
-
-    # cached block-prefill mode: per-sample offsets/lengths ride in SMEM
-    q_offset = (jnp.zeros((b,), jnp.int32) if q_offset is None
-                else q_offset.astype(jnp.int32))
-    kv_len = (jnp.full((b,), sk, jnp.int32) if kv_len is None
-              else kv_len.astype(jnp.int32))
-    return pl.pallas_call(
-        functools.partial(
-            _flash_cached_kernel,
-            scale=1.0 / math.sqrt(d),
-            n_kv_blocks=sk // bk,
-            bq=bq, bk=bk, causal=causal, window=window,
-        ),
+    qo_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, h, qi, ki: (bi, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, d),
+                           lambda bi, h, qi, ki: (bi, h // group, ki, 0))
+    common = dict(
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda bi, h, qi, ki: (bi, ki, h // group, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda bi, h, qi, ki: (bi, ki, h // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, d), lambda bi, h, qi, ki: (bi, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, hq, d), q.dtype),
+        out_specs=qo_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_offset, kv_len, q, k, v)
+    )
+    qkv = tuple(_heads_major(x) for x in (q, k, v))
+
+    if q_offset is None and kv_len is None:
+        out = pl.pallas_call(
+            functools.partial(
+                _flash_kernel,
+                scale=1.0 / math.sqrt(d),
+                n_kv_blocks=sk // bk,
+                bq=bq, bk=bk, causal=causal, window=window,
+            ),
+            in_specs=[qo_spec, kv_spec, kv_spec],
+            **common,
+        )(*qkv)
+        return _heads_major(out)
+
+    # cached block-prefill mode: per-sample offsets/lengths ride in SMEM
+    q_offset = (jnp.zeros((b,), jnp.int32) if q_offset is None
+                else q_offset.astype(jnp.int32))
+    kv_len = (jnp.full((b,), sk, jnp.int32) if kv_len is None
+              else kv_len.astype(jnp.int32))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_cached_kernel,
+            scale=1.0 / math.sqrt(d),
+            n_kv_blocks=sk // bk,
+            bq=bq, bk=bk, causal=causal, window=window,
+        ),
+        in_specs=[smem, smem, qo_spec, kv_spec, kv_spec],
+        **common,
+    )(q_offset, kv_len, *qkv)
+    return _heads_major(out)

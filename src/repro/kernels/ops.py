@@ -139,32 +139,21 @@ def fisher_tapgrads(g, n, mask=None, *, block_c: int = 256):
     sum ``u_{b,(l,c)}``, so the per-channel score is ``Δ = Σ_b u² / (2n)``.
     This routes that reduction through the Pallas fisher kernel by viewing
     the stacked layers as one channel axis — a ``(B, 1, L·C)`` problem with
-    a ones-valued activation operand — which is the TPU-backend schedule of
-    the probe path's device-side reduction (ROADMAP item).  ``mask`` is an
+    a ones-valued activation operand.  The channel axis is zero-padded to
+    a lane-aligned block (a multiple of 128), so every width takes the
+    kernel; padded channels score zero and are sliced off.  ``mask`` is an
     optional (B,) validity vector (bucket-padded episodes); ``n`` the
-    valid-sample normaliser.  Shapes whose flattened channel axis no block
-    tiles fall back to the XLA formula.
+    valid-sample normaliser.
 
     g: (L, B, C) -> (L, C) float32.
     """
     l, b, c = g.shape
-    flat = jnp.moveaxis(g, 0, 1).reshape(b, 1, l * c)
-    bc = _divisor_block(l * c, block_c)
-    # compiled Mosaic path: lane-align the channel block like fisher_auto
-    # does (bc must be a multiple of 128; shrinking by halving preserves
-    # divisibility).  block_d=1 is accepted — the fisher kernel's output
-    # block is (1, block_c) already, so sublane-1 2D tiles are part of its
-    # existing compiled surface (hardware validation is the ROADMAP
-    # follow-up).
-    if not _default_interpret():
-        while bc and bc % 128:
-            bc //= 2
-    if not bc:
-        g2 = flat[:, 0, :].astype(jnp.float32) ** 2
-        if mask is not None:
-            g2 = g2 * mask.astype(jnp.float32)[:, None]
-        return (jnp.sum(g2, axis=0) / (2.0 * n)).reshape(l, c)
-    out = fisher(jnp.ones_like(flat), flat, mask=mask, block_d=1, block_c=bc)
+    lc = l * c
+    flat = jnp.moveaxis(g, 0, 1).reshape(b, 1, lc)
+    bc = min(block_c, -(-lc // 128) * 128)
+    flat = jnp.pad(flat, ((0, 0), (0, 0), (0, -lc % bc)))
+    out = fisher(jnp.ones_like(flat), flat, mask=mask, block_d=1,
+                 block_c=bc)[:lc]
     # the kernel normalises by the (masked) batch count; rescale to 1/(2n)
     valid = jnp.float32(b) if mask is None else jnp.sum(
         mask.astype(jnp.float32))

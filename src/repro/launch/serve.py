@@ -17,6 +17,7 @@ import numpy as np
 
 from .. import api, configs
 from ..models import transformer as T
+from ..utils import enable_compile_cache
 
 
 def main() -> None:
@@ -97,6 +98,7 @@ def main() -> None:
         raise SystemExit("[serve] --fleet requires the fused engine "
                          "(drop --eager)")
 
+    enable_compile_cache()
     cfg = configs.preset_config(args.arch, args.preset)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     faults = None

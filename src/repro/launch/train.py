@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,13 +28,18 @@ from ..data import TokenLoader
 from ..models import transformer as T
 from ..optim import adam, warmup_cosine
 from ..runtime import Trainer, TrainerConfig
+from ..utils import enable_compile_cache
 from .mesh import make_debug_mesh, make_production_mesh
 
 # kept for older callers; the canonical resolver lives in repro.configs
 preset_config = configs.preset_config
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
+    """Parse ``argv`` (default ``sys.argv[1:]``), train, and return what
+    the run produced: ``cfg``, ``params``, ``policy`` (None for
+    ``--mode full``), the final ``train_state``, ``losses`` and per-step
+    wall ``step_seconds`` (the first includes compilation)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--preset", default="smoke", choices=["smoke", "100m", "full"])
@@ -49,19 +55,23 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--production-mesh", action="store_true")
-    args = ap.parse_args()
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the random weights and the token stream")
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.preset_config(args.arch, args.preset)
     mesh = (make_production_mesh() if args.production_mesh
             else make_debug_mesh(len(jax.devices())))
     print(f"[train] arch={cfg.name} mode={args.mode} mesh={dict(mesh.shape)}")
 
-    key = jax.random.PRNGKey(0)
+    key = jax.random.PRNGKey(args.seed)
     params = T.init_params(cfg, key)
     n_params = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
     print(f"[train] params: {n_params/1e6:.1f}M")
 
-    loader = TokenLoader(cfg.vocab, global_batch=args.batch, seq=args.seq, seed=0)
+    loader = TokenLoader(cfg.vocab, global_batch=args.batch, seq=args.seq,
+                         seed=args.seed)
     lr = warmup_cosine(args.lr, args.steps, warmup_steps=max(1, args.steps // 20))
     opt = adam(lr)
     bb = api.backbone(args.arch, preset=args.preset,
@@ -77,6 +87,7 @@ def main() -> None:
                                     mem_kb=args.mem_budget_mb * 1e3,
                                     compute_frac=args.compute_frac)
 
+    policy = None
     with mesh:
         if args.mode == "full":
             step = make_full_train_step(
@@ -121,6 +132,9 @@ def main() -> None:
              else "no new steps (checkpoint already at --steps)")
     print(f"[train] done: {state.step} steps in {dt:.1f}s "
           f"({dt/max(state.step,1)*1e3:.0f} ms/step), {final}")
+    return {"cfg": cfg, "params": params, "policy": policy,
+            "train_state": state.train_state, "losses": trainer.losses,
+            "step_seconds": trainer.step_seconds}
 
 
 if __name__ == "__main__":

@@ -294,7 +294,8 @@ def _apply_block(
         # tap over per-head/per-channel outputs: scale (B, n_units)
         nb = taps["mixer"].shape[-1]
         yb = y.reshape(y.shape[0], y.shape[1], nb, -1)
-        y = (yb * taps["mixer"][:, None, :, None]).reshape(y.shape)
+        y = (yb * taps["mixer"][:, None, :, None].astype(yb.dtype)
+             ).reshape(y.shape)
     x = x + y
 
     if fk != "none":
@@ -336,7 +337,8 @@ def _apply_block(
         if "xattn" in taps:
             nb = taps["xattn"].shape[-1]
             yb = y.reshape(y.shape[0], y.shape[1], nb, -1)
-            y = (yb * taps["xattn"][:, None, :, None]).reshape(y.shape)
+            y = (yb * taps["xattn"][:, None, :, None].astype(yb.dtype)
+                 ).reshape(y.shape)
         x = x + y
     return x, new_cache, aux
 

@@ -65,6 +65,7 @@ class Trainer:
         self.log = log_fn
         self.ckpt = CheckpointManager(cfg.ckpt_dir, keep=cfg.keep)
         self.losses: List[float] = []
+        self.step_seconds: List[float] = []  # wall time of each step
 
     def _save(self, state: TrainerState) -> None:
         self.ckpt.save(
@@ -101,6 +102,7 @@ class Trainer:
             new_train_state, loss = self.step_fn(state.train_state, batch)
             loss = float(loss)
             dt = time.perf_counter() - t0
+            self.step_seconds.append(dt)
             if ewma is None:
                 ewma = dt
             elif dt > self.cfg.straggler_factor * ewma:

@@ -14,11 +14,13 @@ from a device-resident free-list, vLLM-style:
   the ``PendingBuffer`` cumsum-ranked idiom, so the serving ``scan_ticks``
   loop allocates at admission and frees at eviction **on device** — the
   one-host-sync-per-chunk contract survives paging.
-- **Page stores** — per-layer arenas ``(n_pages, page_size, *feat)``.
-  With ``int8=True`` rows are packed to int8 on write with a per-row
-  (per-token) scale and unpacked on read; the quantisation core is the
-  rowwise vectorisation of :func:`repro.optim.compress._quant_one`
-  (absmax/127 + ε), shared via :func:`repro.optim.compress.rowwise_quant`.
+- **Page stores** — per-layer arenas of ``n_pages`` pages, the row axis
+  second-to-last (K/V pages are head-major ``(n_pages, Hkv, page_size,
+  D)``; see :func:`store_init`).  With ``int8=True`` rows are packed to
+  int8 on write with a per-row (per-token) scale and unpacked on read;
+  the quantisation core is the rowwise vectorisation of
+  :func:`repro.optim.compress._quant_one` (absmax/127 + ε), shared via
+  :func:`repro.optim.compress.rowwise_quant`.
   Per-row scales (rather than one scale per page) keep incremental
   single-token writes exact: a page never needs requantising when a new
   row's absmax exceeds the old page maximum.
@@ -278,10 +280,17 @@ def release_run(pool: PagePool, run_table: jax.Array, mask: jax.Array,
 
 def store_init(spec: PagingSpec, feat_shape: Tuple[int, ...], dtype,
                ) -> Dict[str, jax.Array]:
-    """One paged arena: ``pages (n_pages, page_size, *feat)`` plus, for
-    int8 stores, the per-row dequantisation ``scale (n_pages, page_size)``.
+    """One paged arena plus, for int8 stores, the per-row dequantisation
+    ``scale (n_pages, page_size)``.
+
+    The row axis sits second-to-last: ``pages (n_pages, *feat[:-1],
+    page_size, feat[-1])``.  For K/V that is head-major ``(n_pages, Hkv,
+    page_size, D)``, so one head of one page is a ``(page_size, D)`` tile —
+    the block the paged Pallas kernel reads in place.  One-axis features
+    (MLA latents, encoder runs) keep ``(n_pages, page_size, F)``.
     """
-    shape = (spec.n_pages, spec.page_size) + tuple(feat_shape)
+    feat = tuple(feat_shape)
+    shape = (spec.n_pages,) + feat[:-1] + (spec.page_size, feat[-1])
     if spec.int8:
         return {
             "pages": jnp.zeros(shape, jnp.int8),
@@ -297,7 +306,7 @@ def spec_from(cache: Dict[str, Any]) -> PagingSpec:
         if isinstance(store, dict) and "pages" in store:
             pages = store["pages"]
             return PagingSpec(
-                page_size=pages.shape[1], n_pages=pages.shape[0],
+                page_size=pages.shape[-2], n_pages=pages.shape[0],
                 max_pages=cache[PAGE_TABLE_KEY].shape[-1],
                 int8=pages.dtype == jnp.int8)
     raise ValueError("not a paged cache: no 'k'/'ckv' page store found")
@@ -320,21 +329,23 @@ def write_rows(store: Dict[str, jax.Array], table: jax.Array,
     pidx = jnp.clip(logical // ps, 0, spec.max_pages - 1)
     page = jnp.take_along_axis(table, pidx, axis=1)  # (B, S)
     ok = valid & (page >= 0) & (logical >= 0) & (logical < spec.cap)
-    n_rows = spec.n_pages * ps
-    row = jnp.where(ok, page * ps + logical % ps, n_rows).reshape(-1)
-    flat = store["pages"].reshape((n_rows,) + store["pages"].shape[2:])
+    page = jnp.where(ok, page, spec.n_pages).reshape(-1)  # OOB -> dropped
+    slot = (logical % ps).reshape(-1)
+    pages = store["pages"]
+    # (page, :, ..., slot, :): the row axis is second-to-last
+    # (store_init); the advanced indices select one (*feat) row each
+    at = (page,) + (slice(None),) * (pages.ndim - 3) + (slot, slice(None))
     if spec.int8:
         q, scale = compress.rowwise_quant(vals, vals.ndim - 2)
-        flat = flat.at[row].set(
-            q.reshape((b * s,) + q.shape[2:]), mode="drop")
-        sflat = store["scale"].reshape(-1).at[row].set(
-            scale.reshape(-1), mode="drop")
-        return {"pages": flat.reshape(store["pages"].shape),
-                "scale": sflat.reshape(store["scale"].shape)}
-    flat = flat.at[row].set(
-        vals.astype(flat.dtype).reshape((b * s,) + vals.shape[2:]),
-        mode="drop")
-    return {"pages": flat.reshape(store["pages"].shape)}
+        return {
+            "pages": pages.at[at].set(
+                q.reshape((b * s,) + q.shape[2:]), mode="drop"),
+            "scale": store["scale"].at[page, slot].set(
+                scale.reshape(-1), mode="drop"),
+        }
+    return {"pages": pages.at[at].set(
+        vals.astype(pages.dtype).reshape((b * s,) + vals.shape[2:]),
+        mode="drop")}
 
 
 def read_rows(store: Dict[str, jax.Array], table: jax.Array,
@@ -346,7 +357,8 @@ def read_rows(store: Dict[str, jax.Array], table: jax.Array,
     contiguous cache already relies on.  Int8 stores unpack with their
     per-row scales."""
     page = jnp.clip(table, 0, spec.n_pages - 1)      # (B, max_pages)
-    view = store["pages"][page]                       # (B, mp, ps, *feat)
+    # (B, mp, *feat[:-1], ps, feat[-1]) -> (B, mp, ps, *feat)
+    view = jnp.moveaxis(store["pages"][page], -2, 2)
     if spec.int8:
         view = compress.rowwise_dequant(view, store["scale"][page], dtype)
     else:

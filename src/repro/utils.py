@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+import os
 from typing import Any, Callable, Iterator
 
 import jax
@@ -9,6 +10,25 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+# the checkout root: src/repro/utils.py -> ../..
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points call this before their first compile.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing
+    is overridden; otherwise the cache is the fixed ``.jax_cache/`` at the
+    checkout root (a fixed path, so a later run finds the entries).
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_size(tree: PyTree) -> int:

@@ -116,8 +116,9 @@ sys.path.insert(0, "{src}")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh(({n},), ("data",))
+mesh = make_mesh(({n},), ("data",))
 mgr = CheckpointManager("{ckpt}")
 like = {{"w": jnp.zeros((8, 4))}}
 sh = {{"w": NamedSharding(mesh, P("data", None))}}

@@ -19,6 +19,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.dist.sharding import ShardingRules
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.api import ArchConfig
 from repro.core import lm_backbone
@@ -28,7 +29,7 @@ from repro.optim import adam, apply_updates
 cfg = ArchConfig(name="t", family="dense", n_layers=4, d_model=64, vocab=128,
                  n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
                  dtype="float32").validate()
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 rules = ShardingRules(cfg, mesh)
 params = T.init_params(cfg, jax.random.PRNGKey(0))
 params = jax.device_put(params, rules.params(params))

@@ -19,6 +19,7 @@ import pytest
 from repro import api
 from repro.core.backbones import cnn_backbone
 from repro.dist.sharding import FleetShardingRules
+from repro.launch.mesh import make_mesh
 from repro.models import edge_cnn as E
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,7 +52,7 @@ def _run_mesh_parity():
     session = _micro_session()
     rng = np.random.default_rng(0)
     tasks = _het_tasks(rng, [(2, 2), (3, 3), (4, 3), (2, 7)], 8)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     fleet_m = session.adapt_many(tasks, api.RPI_ZERO, iters=2, mesh=mesh)
     rep_m = dict(session.last_fleet_report)
     fleet_h = session.adapt_many(tasks, api.RPI_ZERO, iters=2, mesh=mesh,

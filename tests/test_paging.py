@@ -604,8 +604,9 @@ def test_paged_flash_kernel_matches_gather_oracle():
     B, Sq, Hq, Hkv, D = 3, 8, 4, 2, 16
     ps, n_pages, mp = 4, 10, 6
     spec = PG.PagingSpec(page_size=ps, n_pages=n_pages, max_pages=mp)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, D)), jnp.float32)
+    # head-major arenas, as PG.store_init lays them out
+    kp = jnp.asarray(rng.normal(size=(n_pages, Hkv, ps, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, Hkv, ps, D)), jnp.float32)
     table = np.full((B, mp), -1, np.int32)
     perm = rng.permutation(n_pages)
     off = 0
