@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..optim import Optimizer
 from .backbones import Backbone
 from .criterion import Budget
@@ -34,27 +35,33 @@ from .sparse import make_episode_sparse_scan, make_episode_sparse_step
 
 # Blocking host-transfer telemetry.  Every device->host fetch on the adapt
 # path goes through _fetch()/_fetch_scalar(), so tests and benchmarks can
-# assert the fused path's two-transfer contract instead of trusting it.
-_HOST_SYNCS = [0]
+# assert the fused path's two-transfer contract instead of trusting it.  The
+# count is the process recorder's ``host_syncs`` counter.
 
 
 def host_sync_count() -> int:
     """Blocking device->host transfer events since the last reset."""
-    return _HOST_SYNCS[0]
+    return telemetry.counter("host_syncs")
 
 
 def reset_host_sync_count() -> None:
-    _HOST_SYNCS[0] = 0
+    telemetry.reset_counter("host_syncs")
 
 
 def _fetch(tree: Any) -> Any:
-    """Materialise a pytree on the host: one blocking transfer event."""
-    _HOST_SYNCS[0] += 1
-    return jax.tree_util.tree_map(np.asarray, tree)
+    """Materialise a pytree on the host: one blocking transfer event.
+    Waits for the whole tree, then copies each leaf."""
+    tree = jax.block_until_ready(tree)
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    telemetry.count("host_syncs")
+    telemetry.count("arrays_fetched", len(leaves))
+    return jax.tree_util.tree_unflatten(treedef,
+                                        [np.asarray(x) for x in leaves])
 
 
 def _fetch_scalar(x: Any) -> float:
-    _HOST_SYNCS[0] += 1
+    telemetry.count("host_syncs")
+    telemetry.count("arrays_fetched")
     return float(x)
 
 
@@ -66,9 +73,12 @@ def _fetch_local(tree: Any) -> Any:
     shards — every host pulls only its own rows of the task axis — and
     reassembles them in task order; replicated leaves (probe taps, loss
     scalars broadcast over hosts) dedupe to a single shard read.  Counts
-    as one blocking transfer event, same contract as :func:`_fetch`.
+    as one blocking transfer event, same contract as :func:`_fetch`, and
+    likewise waits for the whole tree before it copies.
     """
-    _HOST_SYNCS[0] += 1
+    tree = jax.block_until_ready(tree)
+    telemetry.count("host_syncs")
+    telemetry.count("arrays_fetched", len(jax.tree_util.tree_leaves(tree)))
 
     def pull(x):
         shards = getattr(x, "addressable_shards", None)
@@ -152,7 +162,7 @@ def _probe_and_select(
         potentials, chans, fisher_dt = fisher_probe(
             backbone, params, probe_loss, support, n
         )
-        _HOST_SYNCS[0] += 1
+        telemetry.count("host_syncs")
         transfers = 1
     policy = select_policy(
         backbone.unit_costs, potentials, chans, budget,

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..optim import Optimizer, adam
 from .adapt import (
     AdaptResult, adapt_task, _fetch, _fetch_local, _fetch_scalar,
@@ -580,10 +581,44 @@ class TinyTrainSession:
         same code over its own episode shard.
 
         A summary of the grouping (buckets, policy structures, compiled
-        scans) is recorded in ``self.last_fleet_report``.
+        scans, host syncs) is recorded in ``self.last_fleet_report``.
+
+        Each call is one ``adapt_many`` span of :mod:`repro.telemetry`,
+        tiled by the spans of its phases: ``adapt_many.bucket``, then per
+        episode group ``.probe.stack``, ``.probe.run`` (dispatch up to the
+        device's completion), ``.probe.fetch`` (the host copy) and
+        ``.select``, then per run group ``.finetune.stack``,
+        ``.finetune.run``, ``.finetune.fetch`` and ``.finish``.  A task's
+        ``fisher_seconds`` is its group's probe run and fetch, and its
+        ``train_seconds`` its group's fine-tune run and fetch, each shared
+        over the group's tasks.
         """
         if not tasks:
             return []
+        with telemetry.span("adapt_many", tasks=len(tasks)) as root:
+            syncs0 = telemetry.counter("host_syncs")
+            arrays0 = telemetry.counter("arrays_fetched")
+            out, report = self._adapt_many(
+                root, tasks, profile, criterion=criterion, iters=iters,
+                shard_channels=shard_channels,
+                policy_override=policy_override, bucket=bucket, mesh=mesh,
+                hosts=hosts)
+            counts = root.counts
+            counts["host_syncs"] = telemetry.counter("host_syncs") - syncs0
+            counts["arrays_fetched"] = (telemetry.counter("arrays_fetched")
+                                        - arrays0)
+        self.last_fleet_report = dict(
+            tasks=counts["tasks"], groups=counts["finetune_groups"],
+            probe_groups=counts["probe_groups"],
+            host_syncs=counts["host_syncs"], **report)
+        return out
+
+    def _adapt_many(self, root, tasks, profile, *, criterion, iters,
+                    shard_channels, policy_override, bucket, mesh, hosts
+                    ) -> Tuple[List["Adaptation"], Dict[str, Any]]:
+        """The body of :meth:`adapt_many` inside its root span ``root``:
+        returns the adaptations and the grouping summary, and sets the
+        root's ``probe_groups`` and ``finetune_groups`` counts."""
         for t in tasks:
             self._check_task(t)
         if isinstance(profile, str):
@@ -618,12 +653,14 @@ class TinyTrainSession:
                     f"hosts ({hosts}) must divide the mesh data size "
                     f"({rules.dp_size}) so device shards never straddle "
                     "host blocks")
+        fetch = _fetch_local if hosted else _fetch
 
         # bucket (or pass through) every episode once; keys come from the
         # padded trees so one bucket serves any way/shot mix inside it
-        eps = [_bucket_episode(t) if bucket else (t.support, t.pseudo_query)
-               for t in tasks]
-        keys = [_episode_shape_key(sup, pq) for sup, pq in eps]
+        with telemetry.span("adapt_many.bucket"):
+            eps = [_bucket_episode(t) if bucket
+                   else (t.support, t.pseudo_query) for t in tasks]
+            keys = [_episode_shape_key(sup, pq) for sup, pq in eps]
 
         fisher_dt = [0.0] * len(tasks)
         transfers = [0.0] * len(tasks)  # per-task share of group fetches
@@ -670,6 +707,7 @@ class TinyTrainSession:
                     rules.assemble_tasks(pq_b),
                     rules.assemble_tasks(ex_b))
 
+        root.counts["probe_groups"] = 0
         if policy_override is not None:
             policies = [policy_override] * len(tasks)
             method = (f"override:"
@@ -688,111 +726,119 @@ class TinyTrainSession:
                 # task; only the probe batching is lost)
                 from .adapt import _probe_and_select
 
-                for i, t in enumerate(tasks):
-                    policies[i], fisher_dt[i], tr = _probe_and_select(
-                        self.backbone, self.params, t.support,
-                        t.pseudo_query, budget, max_way=self.max_way,
-                        criterion=mode, shard_channels=shard_channels,
-                        step_cache=self.step_cache)
-                    transfers[i] = float(tr)
+                with telemetry.span("adapt_many.select"):
+                    for i, t in enumerate(tasks):
+                        policies[i], fisher_dt[i], tr = _probe_and_select(
+                            self.backbone, self.params, t.support,
+                            t.pseudo_query, budget, max_way=self.max_way,
+                            criterion=mode, shard_channels=shard_channels,
+                            step_cache=self.step_cache)
+                        transfers[i] = float(tr)
             else:
                 shape_groups = _group_indices(keys)
+                root.counts["probe_groups"] = len(shape_groups)
                 for idxs in shape_groups.values():
-                    if hosted:
-                        sup, pq, ns = host_ingest(
-                            idxs,
-                            lambda i: np.float32(tasks[i].n_support))
-                    else:
-                        sup, pq = stacked(idxs)
-                        ns = jnp.asarray([tasks[i].n_support for i in idxs],
-                                         jnp.float32)
-                    batch_pad = next(v.shape[0] for v in
-                                     jax.tree_util.tree_leaves(eps[idxs[0]][0]))
-                    taps = self.backbone.make_taps(batch_pad)
-                    if not hosted:
-                        sup, pq, ns = mesh_pad(len(idxs), sup, pq, ns)
-                    if rules is not None:
-                        taps = rules.place_replicated(taps)
-                    t0 = time.perf_counter()
-                    fetch = _fetch_local if hosted else _fetch
-                    chans_all = fetch(self.step_cache.probe_fisher_batch()(
-                        params_run, sup, pq, taps, ns))
-                    dt = (time.perf_counter() - t0) / len(idxs)
-                    for j, i in enumerate(idxs):
-                        chans = {k: v[j] for k, v in chans_all.items()}
-                        policies[i] = select_policy(
-                            self.backbone.unit_costs,
-                            potentials_from_chans(self.backbone.unit_costs,
-                                                  chans),
-                            chans, budget, criterion=mode,
-                            shard_channels=shard_channels)
-                        fisher_dt[i] = dt
-                        transfers[i] = 1.0 / len(idxs)
+                    with telemetry.span("adapt_many.probe.stack"):
+                        if hosted:
+                            sup, pq, ns = host_ingest(
+                                idxs,
+                                lambda i: np.float32(tasks[i].n_support))
+                        else:
+                            sup, pq = stacked(idxs)
+                            ns = jnp.asarray(
+                                [tasks[i].n_support for i in idxs],
+                                jnp.float32)
+                        batch_pad = next(v.shape[0] for v in
+                                         jax.tree_util.tree_leaves(
+                                             eps[idxs[0]][0]))
+                        taps = self.backbone.make_taps(batch_pad)
+                        if not hosted:
+                            sup, pq, ns = mesh_pad(len(idxs), sup, pq, ns)
+                        if rules is not None:
+                            taps = rules.place_replicated(taps)
+                    with telemetry.span("adapt_many.probe.run") as run_sp:
+                        chans_dev = jax.block_until_ready(
+                            self.step_cache.probe_fisher_batch()(
+                                params_run, sup, pq, taps, ns))
+                    with telemetry.span(
+                            "adapt_many.probe.fetch") as fetch_sp:
+                        chans_all = fetch(chans_dev)
+                    dt = (run_sp.seconds + fetch_sp.seconds) / len(idxs)
+                    with telemetry.span("adapt_many.select"):
+                        for j, i in enumerate(idxs):
+                            chans = {k: v[j] for k, v in chans_all.items()}
+                            policies[i] = select_policy(
+                                self.backbone.unit_costs,
+                                potentials_from_chans(
+                                    self.backbone.unit_costs, chans),
+                                chans, budget, criterion=mode,
+                                shard_channels=shard_channels)
+                            fisher_dt[i] = dt
+                            transfers[i] = 1.0 / len(idxs)
 
         # one vmapped scan per (bucket, policy structure) group
         out: List[Optional[Adaptation]] = [None] * len(tasks)
         run_groups = _group_indices(
             [(k, self.step_cache._key(p)) for k, p in zip(keys, policies)])
+        root.counts["finetune_groups"] = len(run_groups)
         compiles_before = self.step_cache.fleet_scan_compiles()
         for idxs in run_groups.values():
             pol0 = policies[idxs[0]]
             n_real = len(idxs)
-            if hosted:
-                sup, pq, ci = host_ingest(
-                    idxs,
-                    lambda i: self.step_cache.chan_idx_arrays(policies[i]))
-            else:
-                sup, pq = stacked(idxs)
-                ci = _stack_trees(
-                    [self.step_cache.chan_idx_arrays(policies[i])
-                     for i in idxs])
-                sup, pq, ci = mesh_pad(n_real, sup, pq, ci)
+            padded = rules is not None and rules.padded_count(n_real) != n_real
+            with telemetry.span("adapt_many.finetune.stack"):
+                if hosted:
+                    sup, pq, ci = host_ingest(
+                        idxs,
+                        lambda i: self.step_cache.chan_idx_arrays(
+                            policies[i]))
+                else:
+                    sup, pq = stacked(idxs)
+                    ci = _stack_trees(
+                        [self.step_cache.chan_idx_arrays(policies[i])
+                         for i in idxs])
+                    sup, pq, ci = mesh_pad(n_real, sup, pq, ci)
             # publish the fleet mesh so vmap_scan_steps picks the
             # shard_map path (task axis split across the mesh's data axes)
-            with dist_context.sharding_context(fleet_mesh=mesh):
+            with dist_context.sharding_context(fleet_mesh=mesh), \
+                    telemetry.span("adapt_many.finetune.run") as run_sp:
                 run = self.step_cache.vmap_scan_steps(pol0, iters)
-                t0 = time.perf_counter()
-                d_stack, _, loss_stack, skip_stack = run(
-                    params_run, sup, pq, ci)
-            if hosted:
-                # collective-free: each host fetches only its addressable
-                # shards, then drops the padding rows host-side
-                d_host, losses, skips = _fetch_local(
-                    (d_stack, loss_stack, skip_stack))
-                if rules.padded_count(n_real) != n_real:
-                    d_host = jax.tree_util.tree_map(
-                        lambda x: x[:n_real], d_host)
-                    losses = losses[:n_real]
-                    skips = skips[:n_real]
-            else:
-                if rules is not None and rules.padded_count(n_real) != n_real:
+                d_stack, _, loss_stack, skip_stack = jax.block_until_ready(
+                    run(params_run, sup, pq, ci))
+            with telemetry.span("adapt_many.finetune.fetch") as fetch_sp:
+                if padded and not hosted:
                     d_stack = jax.tree_util.tree_map(
                         lambda x: x[:n_real], d_stack)
                     loss_stack = loss_stack[:n_real]
                     skip_stack = skip_stack[:n_real]
-                # one barrier fetch per group; per-task views are numpy
-                # slices
-                d_host, losses, skips = _fetch(
+                # one barrier fetch per group (collective-free when hosted:
+                # each host reads only its addressable shards); per-task
+                # views are numpy slices
+                d_host, losses, skips = fetch(
                     (d_stack, loss_stack, skip_stack))
-            dt = (time.perf_counter() - t0) / len(idxs)
-            for j, i in enumerate(idxs):
-                res = AdaptResult(
-                    deltas=jax.tree_util.tree_map(lambda x, _j=j: x[_j],
-                                                  d_host),
-                    policy=policies[i], fisher_seconds=fisher_dt[i],
-                    train_seconds=dt,
-                    losses=[float(x) for x in losses[j]],
-                    host_transfers=transfers[i] + 1.0 / len(idxs),
-                    skipped_steps=int(np.sum(skips[j])))
-                out[i] = self._wrap(method, tasks[i], prof, res,
-                                    budget=budget)
-        self.last_fleet_report = {
-            "tasks": len(tasks),
+                if padded and hosted:
+                    d_host = jax.tree_util.tree_map(
+                        lambda x: x[:n_real], d_host)
+                    losses = losses[:n_real]
+                    skips = skips[:n_real]
+            dt = (run_sp.seconds + fetch_sp.seconds) / n_real
+            with telemetry.span("adapt_many.finish"):
+                for j, i in enumerate(idxs):
+                    res = AdaptResult(
+                        deltas=jax.tree_util.tree_map(
+                            lambda x, _j=j: x[_j], d_host),
+                        policy=policies[i], fisher_seconds=fisher_dt[i],
+                        train_seconds=dt,
+                        losses=[float(x) for x in losses[j]],
+                        host_transfers=transfers[i] + 1.0 / n_real,
+                        skipped_steps=int(np.sum(skips[j])))
+                    out[i] = self._wrap(method, tasks[i], prof, res,
+                                        budget=budget)
+        report = {
             "bucketed": bucket,
             "buckets": len(set(keys)),
             "policy_structures": len({self.step_cache._key(p)
                                       for p in policies}),
-            "groups": len(run_groups),
             "scan_compiles": (self.step_cache.fleet_scan_compiles()
                               - compiles_before),
             "mesh_axes": dict(mesh.shape) if mesh is not None else None,
@@ -800,7 +846,7 @@ class TinyTrainSession:
             "ingestion": ("per-host" if hosted
                           else "global" if mesh is not None else "local"),
         }
-        return out
+        return out, report
 
     def evaluate(self, task: Task, adaptation: Optional[Adaptation] = None
                  ) -> float:

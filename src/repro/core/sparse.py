@@ -271,8 +271,14 @@ class EpisodeStepCache:
         axes on supports/queries/ns; params and taps are broadcast.
         """
         if self._probe_fisher_batch is None:
-            self._probe_fisher_batch = jax.jit(jax.vmap(
-                self._probe_fisher_fn(), in_axes=(None, 0, 0, None, 0)))
+            batched = jax.vmap(self._probe_fisher_fn(),
+                               in_axes=(None, 0, 0, None, 0))
+
+            # named for the device trace: its program is jit_fleet_probe
+            def fleet_probe(params, supports, queries, taps, ns):
+                return batched(params, supports, queries, taps, ns)
+
+            self._probe_fisher_batch = jax.jit(fleet_probe)
         return self._probe_fisher_batch
 
     @staticmethod
@@ -437,7 +443,11 @@ class EpisodeStepCache:
                         local, mesh=mesh, in_specs=(P(), ts, ts, ts),
                         out_specs=ts, check_vma=False)
 
-            self._vscans[key] = jax.jit(fleet)
+            # named for the device trace: its program is jit_fleet_finetune
+            def fleet_finetune(params, support, query, chan_idx):
+                return fleet(params, support, query, chan_idx)
+
+            self._vscans[key] = jax.jit(fleet_finetune)
         return self._vscans[key]
 
     def block_score(self, block: int = 32):

@@ -76,5 +76,6 @@ def fisher_pallas(
         out_shape=jax.ShapeDtypeStruct((1, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
         interpret=interpret,
+        name="fisher_channel_scores",
     )(a, g)
     return out[0]

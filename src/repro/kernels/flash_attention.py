@@ -280,6 +280,7 @@ def flash_attention_paged_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         interpret=interpret,
+        name="paged_flash_attention",
     )(page_table.astype(jnp.int32), q_offset.astype(jnp.int32),
       kv_len.astype(jnp.int32), _heads_major(q), k_pages, v_pages)
     return _heads_major(out)
@@ -335,6 +336,7 @@ def flash_attention_pallas(
                 bq=bq, bk=bk, causal=causal, window=window,
             ),
             in_specs=[qo_spec, kv_spec, kv_spec],
+            name="flash_attention",
             **common,
         )(*qkv)
         return _heads_major(out)
@@ -353,6 +355,7 @@ def flash_attention_pallas(
             bq=bq, bk=bk, causal=causal, window=window,
         ),
         in_specs=[smem, smem, qo_spec, kv_spec, kv_spec],
+        name="cached_flash_attention",
         **common,
     )(q_offset, kv_len, *qkv)
     return _heads_major(out)

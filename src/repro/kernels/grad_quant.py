@@ -62,6 +62,7 @@ def grad_quant_pallas(
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
+        name="grad_absmax",
     )(g2, e2)
     scale = absmax / 127.0 + 1e-12
 
@@ -82,6 +83,7 @@ def grad_quant_pallas(
             jax.ShapeDtypeStruct((rows, block), jnp.float32),
         ],
         interpret=interpret,
+        name="grad_quant_int8",
     )(g2, e2, scale)
 
     q = q.reshape(-1)[:n].reshape(shape)

@@ -112,5 +112,6 @@ def ssd_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_chunk_scan",
     )(x, dt, a2, bmat, cmat)
     return y, st

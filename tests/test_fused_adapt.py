@@ -151,3 +151,82 @@ class TestCompileAndTransferBudget:
                               fused=False)
         assert adapt_mod.host_sync_count() == 1 + 3  # probe + per-iter
         assert a.host_transfers == 4
+
+
+PHASES_PER_PROBE_GROUP = ("adapt_many.probe.stack", "adapt_many.probe.run",
+                          "adapt_many.probe.fetch", "adapt_many.select")
+PHASES_PER_RUN_GROUP = ("adapt_many.finetune.stack",
+                        "adapt_many.finetune.run",
+                        "adapt_many.finetune.fetch", "adapt_many.finish")
+
+
+@pytest.fixture(scope="module")
+def fleet_spans(cnn_session, cnn_tasks):
+    """One warm adapt_many call's root span, its children and results,
+    and the host-sync counter's increase over the call."""
+    from repro import telemetry
+
+    tasks = cnn_tasks + cnn_tasks[:1]
+    cnn_session.adapt_many(tasks, api.RPI_ZERO, iters=3)  # compile
+    syncs0 = adapt_mod.host_sync_count()
+    out = cnn_session.adapt_many(tasks, api.RPI_ZERO, iters=3)
+    syncs = adapt_mod.host_sync_count() - syncs0
+    recs = list(telemetry.RECORDER.records)
+    root = [r for r in recs if r.name == "adapt_many"][-1]
+    kids = [r for r in recs if r.call_id == root.id and r is not root]
+    return root, kids, out, syncs
+
+
+class TestFleetSpans:
+    def test_every_phase_is_spanned_per_group(self, fleet_spans,
+                                              cnn_session):
+        root, kids, out, _ = fleet_spans
+        c = root.counts
+        assert c["tasks"] == len(out) == 4
+        assert c["probe_groups"] >= 1 and c["finetune_groups"] >= 1
+        names = [k.name for k in kids]
+        assert names.count("adapt_many.bucket") == 1
+        for n in PHASES_PER_PROBE_GROUP:
+            assert names.count(n) == c["probe_groups"], n
+        for n in PHASES_PER_RUN_GROUP:
+            assert names.count(n) == c["finetune_groups"], n
+        assert len(kids) == 1 + 4 * (c["probe_groups"]
+                                     + c["finetune_groups"])
+        assert all(k.parent == root.id for k in kids)
+        rep = cnn_session.last_fleet_report
+        assert rep["groups"] == c["finetune_groups"]
+        assert rep["probe_groups"] == c["probe_groups"]
+        assert rep["tasks"] == c["tasks"]
+
+    def test_children_tile_their_root(self, fleet_spans):
+        from repro import telemetry
+
+        root, kids, _, _ = fleet_spans
+        for k in kids:
+            assert root.start <= k.start <= k.end <= root.end
+        order = sorted(kids, key=lambda k: k.start)
+        for a, b in zip(order, order[1:]):
+            assert a.end <= b.start  # phases run one after another
+        assert telemetry.self_seconds(root, kids) >= 0
+
+    def test_fisher_and_train_seconds_are_the_spans_shares(self,
+                                                           fleet_spans):
+        root, kids, out, _ = fleet_spans
+        assert root.counts["probe_groups"] == 1  # one padded shape
+        probe = sum(k.seconds for k in kids if k.name in (
+            "adapt_many.probe.run", "adapt_many.probe.fetch"))
+        for a in out:
+            assert a.fisher_seconds == pytest.approx(probe / len(out),
+                                                     rel=1e-12)
+        train = sum(k.seconds for k in kids if k.name in (
+            "adapt_many.finetune.run", "adapt_many.finetune.fetch"))
+        assert sum(a.train_seconds for a in out) == pytest.approx(
+            train, rel=1e-9)
+
+    def test_root_counts_the_calls_host_syncs(self, fleet_spans):
+        root, _, _, syncs = fleet_spans
+        c = root.counts
+        assert c["host_syncs"] == syncs
+        # one probe fetch per episode group, one fetch per run group
+        assert syncs == c["probe_groups"] + c["finetune_groups"]
+        assert c["arrays_fetched"] >= 3 * c["finetune_groups"]
