@@ -220,7 +220,8 @@ class Task:
 
 
 def _stack_trees(trees: List[Any]) -> Any:
-    """Stack a list of identically-shaped pytrees along a new task axis."""
+    """Stack a list of identically-shaped pytrees along a new task axis
+    (traced inside :func:`fleet_stack_episodes`)."""
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
@@ -241,6 +242,10 @@ def _group_indices(keys: List[Any]) -> Dict[Any, List[int]]:
     for i, k in enumerate(keys):
         groups.setdefault(k, []).append(i)
     return groups
+
+
+# counters of repro.telemetry an adapt_many call records on its root span
+_FLEET_COUNTERS = ("host_syncs", "arrays_fetched", "prep_programs")
 
 
 # Bucketed episode padding: heterogeneous way/shot traffic is padded up to
@@ -283,29 +288,49 @@ def _pad_episode_rows(ep: Dict[str, jax.Array], rows: int
     return out
 
 
+# The fleet's input preparation runs as compiled programs, one call each,
+# never as one eager op per leaf and task.  Their compile keys hold shapes
+# alone: the pad's the raw episode shape and the bucket rows, the stack's
+# the task count and the bucket shape, so no key depends on the order of
+# the tasks inside a group.  Each call counts once in the ``prep_programs``
+# counter of :mod:`repro.telemetry`.
+
+
+def fleet_pad_episode(support: Any, pseudo_query: Any, rows: int
+                      ) -> Tuple[Any, Any]:
+    """Both episode trees of a task padded to ``rows`` (a static row
+    count)."""
+    return (_pad_episode_rows(support, rows),
+            _pad_episode_rows(pseudo_query, rows))
+
+
+def fleet_stack_episodes(supports: List[Any], pseudo_queries: List[Any]
+                         ) -> Tuple[Any, Any]:
+    """A group's support and pseudo-query trees, each stacked along a new
+    task axis."""
+    return _stack_trees(supports), _stack_trees(pseudo_queries)
+
+
+# named for the device trace: jit_fleet_pad_episode, jit_fleet_stack_episodes
+_fleet_pad_episode = jax.jit(fleet_pad_episode, static_argnums=2)
+_fleet_stack_episodes = jax.jit(fleet_stack_episodes)
+
+
 def _bucket_episode(task: Task) -> Tuple[Any, Any]:
     """(support, pseudo_query) of a task, padded to one shared bucket.
 
     Both sets pad to the same row count because the Fisher taps are sized
-    once per episode and threaded through both forward passes.
+    once per episode and threaded through both forward passes.  One
+    compiled call pads both; a task already on its bucket is returned as
+    it is, with no call.
     """
-    rows = max(
-        int(v.shape[0])
-        for tree in (task.support, task.pseudo_query)
-        for v in jax.tree_util.tree_leaves(tree)
-    )
-    target = _bucket_rows(rows)
-    return (_pad_episode_rows(task.support, target),
-            _pad_episode_rows(task.pseudo_query, target))
-
-
-def _pad_task_axis(tree: Any, reps: int) -> Any:
-    """Pad a task-stacked pytree's leading axis by repeating the last task
-    (mesh-divisibility padding; the copies' results are sliced off before
-    the fetch)."""
-    return jax.tree_util.tree_map(
-        lambda x: jnp.concatenate(
-            [x, jnp.repeat(x[-1:], reps, axis=0)]), tree)
+    trees = (task.support, task.pseudo_query)
+    rows = [int(v.shape[0]) for v in jax.tree_util.tree_leaves(trees)]
+    target = _bucket_rows(max(rows))
+    if all(n == target for n in rows):
+        return trees
+    telemetry.count("prep_programs")
+    return _fleet_pad_episode(task.support, task.pseudo_query, target)
 
 
 # ---------------------------------------------------------------------------
@@ -581,7 +606,12 @@ class TinyTrainSession:
         same code over its own episode shard.
 
         A summary of the grouping (buckets, policy structures, compiled
-        scans, host syncs) is recorded in ``self.last_fleet_report``.
+        scans, host syncs, prep programs) is recorded in
+        ``self.last_fleet_report``.  Each group's inputs are prepared by
+        compiled calls alone (``prep_programs``: a pad per task off its
+        bucket, a stack per group, a transfer per channel-index leaf);
+        the probe program makes its taps and valid counts itself, so the
+        call's only host syncs are its group fetches.
 
         Each call is one ``adapt_many`` span of :mod:`repro.telemetry`,
         tiled by the spans of its phases: ``adapt_many.bucket``, then per
@@ -596,21 +626,20 @@ class TinyTrainSession:
         if not tasks:
             return []
         with telemetry.span("adapt_many", tasks=len(tasks)) as root:
-            syncs0 = telemetry.counter("host_syncs")
-            arrays0 = telemetry.counter("arrays_fetched")
+            before = {k: telemetry.counter(k) for k in _FLEET_COUNTERS}
             out, report = self._adapt_many(
                 root, tasks, profile, criterion=criterion, iters=iters,
                 shard_channels=shard_channels,
                 policy_override=policy_override, bucket=bucket, mesh=mesh,
                 hosts=hosts)
             counts = root.counts
-            counts["host_syncs"] = telemetry.counter("host_syncs") - syncs0
-            counts["arrays_fetched"] = (telemetry.counter("arrays_fetched")
-                                        - arrays0)
+            counts.update({k: telemetry.counter(k) - n
+                           for k, n in before.items()})
         self.last_fleet_report = dict(
             tasks=counts["tasks"], groups=counts["finetune_groups"],
             probe_groups=counts["probe_groups"],
-            host_syncs=counts["host_syncs"], **report)
+            host_syncs=counts["host_syncs"],
+            prep_programs=counts["prep_programs"], **report)
         return out
 
     def _adapt_many(self, root, tasks, profile, *, criterion, iters,
@@ -664,6 +693,34 @@ class TinyTrainSession:
 
         fisher_dt = [0.0] * len(tasks)
         transfers = [0.0] * len(tasks)  # per-task share of group fetches
+
+        def rows_of(idxs, lo=0, hi=None):
+            """Tasks of rows ``lo..hi`` of a group's task axis, padded to
+            the mesh data size: row ``p`` holds task
+            ``idxs[min(p, n_real - 1)]`` (the copies of the last task are
+            sliced off before the fetch)."""
+            n_real = len(idxs)
+            if hi is None:
+                hi = n_real if rules is None else rules.padded_count(n_real)
+            return [idxs[min(p, n_real - 1)] for p in range(lo, hi)]
+
+        def stack(rows):
+            """The rows' episodes stacked in one compiled call."""
+            telemetry.count("prep_programs")
+            return _fleet_stack_episodes([eps[i][0] for i in rows],
+                                         [eps[i][1] for i in rows])
+
+        def chan_idx(rows):
+            """The rows' channel indices, stacked on the host (numpy):
+            each leaf is one transfer to the device."""
+            pols = [policies[i] for i in rows]
+            ci = {lid: {k: np.stack([p.channel_idx[lid][k] for p in pols])
+                        for k in kinds}
+                  for lid, kinds in pols[0].channel_idx.items()}
+            telemetry.count("prep_programs",
+                            len(jax.tree_util.tree_leaves(ci)))
+            return ci
+
         # stacked episode pytrees keyed by task-index tuple, so the probe
         # and fine-tune loops ship each task's data to the device once
         stack_cache: Dict[Tuple[int, ...], Tuple[Any, Any]] = {}
@@ -671,41 +728,27 @@ class TinyTrainSession:
         def stacked(idxs):
             key = tuple(idxs)
             if key not in stack_cache:
-                stack_cache[key] = (
-                    _stack_trees([eps[i][0] for i in idxs]),
-                    _stack_trees([eps[i][1] for i in idxs]),
-                )
+                trees = stack(rows_of(idxs))
+                if rules is not None:
+                    trees = tuple(rules.place_tasks(t) for t in trees)
+                stack_cache[key] = trees
             return stack_cache[key]
 
-        def mesh_pad(n_real, *trees):
-            """Pad task axes to the mesh data size and place on devices."""
-            if rules is None:
-                return trees
-            reps = rules.padded_count(n_real) - n_real
-            if reps:
-                trees = tuple(_pad_task_axis(t, reps) for t in trees)
-            return tuple(rules.place_tasks(t) for t in trees)
-
-        def host_ingest(idxs, extra_row):
+        def host_ingest(idxs, with_chan_idx):
             """Per-host episode ingestion for one group.
 
-            Each of the H hosts builds (and locally pads) only its own
-            contiguous block of the task axis — global row ``p`` carries
-            task ``idxs[min(p, n_real - 1)]``, the same values the global
-            repeat-last padding produces — then the global arrays are
-            assembled shard-by-shard, no host holding the full stack.
-            Returns placed (sup, pq, extra) global arrays."""
-            n_real = len(idxs)
-            n_pad = rules.padded_count(n_real)
-            sup_b, pq_b, ex_b = [], [], []
-            for lo, hi in rules.host_blocks(n_pad, hosts):
-                rows = [idxs[min(p, n_real - 1)] for p in range(lo, hi)]
-                sup_b.append(_stack_trees([eps[i][0] for i in rows]))
-                pq_b.append(_stack_trees([eps[i][1] for i in rows]))
-                ex_b.append(_stack_trees([extra_row(i) for i in rows]))
-            return (rules.assemble_tasks(sup_b),
-                    rules.assemble_tasks(pq_b),
-                    rules.assemble_tasks(ex_b))
+            Each of the H hosts builds only its own contiguous block of
+            the padded task axis (:func:`rows_of`), the same values the
+            global padding produces, then the global arrays are assembled
+            shard-by-shard, no host holding the full stack.  Returns
+            placed (sup, pq) global arrays, and the channel indices when
+            ``with_chan_idx``."""
+            blocks = []
+            for lo, hi in rules.host_blocks(len(rows_of(idxs)), hosts):
+                rows = rows_of(idxs, lo, hi)
+                blocks.append(stack(rows) + (
+                    (chan_idx(rows),) if with_chan_idx else ()))
+            return tuple(rules.assemble_tasks(list(b)) for b in zip(*blocks))
 
         root.counts["probe_groups"] = 0
         if policy_override is not None:
@@ -738,28 +781,15 @@ class TinyTrainSession:
                 shape_groups = _group_indices(keys)
                 root.counts["probe_groups"] = len(shape_groups)
                 for idxs in shape_groups.values():
+                    # the probe program makes its taps and each task's
+                    # valid count itself: the host sends the episodes only
                     with telemetry.span("adapt_many.probe.stack"):
-                        if hosted:
-                            sup, pq, ns = host_ingest(
-                                idxs,
-                                lambda i: np.float32(tasks[i].n_support))
-                        else:
-                            sup, pq = stacked(idxs)
-                            ns = jnp.asarray(
-                                [tasks[i].n_support for i in idxs],
-                                jnp.float32)
-                        batch_pad = next(v.shape[0] for v in
-                                         jax.tree_util.tree_leaves(
-                                             eps[idxs[0]][0]))
-                        taps = self.backbone.make_taps(batch_pad)
-                        if not hosted:
-                            sup, pq, ns = mesh_pad(len(idxs), sup, pq, ns)
-                        if rules is not None:
-                            taps = rules.place_replicated(taps)
+                        sup, pq = (host_ingest(idxs, False) if hosted
+                                   else stacked(idxs))
                     with telemetry.span("adapt_many.probe.run") as run_sp:
                         chans_dev = jax.block_until_ready(
                             self.step_cache.probe_fisher_batch()(
-                                params_run, sup, pq, taps, ns))
+                                params_run, sup, pq))
                     with telemetry.span(
                             "adapt_many.probe.fetch") as fetch_sp:
                         chans_all = fetch(chans_dev)
@@ -788,16 +818,12 @@ class TinyTrainSession:
             padded = rules is not None and rules.padded_count(n_real) != n_real
             with telemetry.span("adapt_many.finetune.stack"):
                 if hosted:
-                    sup, pq, ci = host_ingest(
-                        idxs,
-                        lambda i: self.step_cache.chan_idx_arrays(
-                            policies[i]))
+                    sup, pq, ci = host_ingest(idxs, True)
                 else:
                     sup, pq = stacked(idxs)
-                    ci = _stack_trees(
-                        [self.step_cache.chan_idx_arrays(policies[i])
-                         for i in idxs])
-                    sup, pq, ci = mesh_pad(n_real, sup, pq, ci)
+                    ci = chan_idx(rows_of(idxs))
+                    ci = (jax.device_put(ci) if rules is None
+                          else rules.place_tasks(ci))
             # publish the fleet mesh so vmap_scan_steps picks the
             # shard_map path (task axis split across the mesh's data axes)
             with dist_context.sharding_context(fleet_mesh=mesh), \

@@ -267,15 +267,23 @@ class EpisodeStepCache:
     def probe_fisher_batch(self):
         """Vmapped probe: one dispatch scores a whole fleet of tasks.
 
-        pfb(params, supports, queries, taps, ns) with task-stacked leading
-        axes on supports/queries/ns; params and taps are broadcast.
+        pfb(params, supports, queries) with task-stacked leading axes on
+        supports/queries; params are broadcast.  The program builds the
+        rest of the per-task probe's arguments itself: the taps for the
+        stacked row count (constants, broadcast) and each task's valid
+        count ``n``, its support labels >= 0 (``Task.n_support``; padded
+        and repeated rows count as their task does).
         """
         if self._probe_fisher_batch is None:
             batched = jax.vmap(self._probe_fisher_fn(),
                                in_axes=(None, 0, 0, None, 0))
+            backbone = self.backbone
 
             # named for the device trace: its program is jit_fleet_probe
-            def fleet_probe(params, supports, queries, taps, ns):
+            def fleet_probe(params, supports, queries):
+                labels = supports["episode_labels"]
+                taps = backbone.make_taps(labels.shape[1])
+                ns = jnp.sum(labels >= 0, axis=1).astype(jnp.float32)
                 return batched(params, supports, queries, taps, ns)
 
             self._probe_fisher_batch = jax.jit(fleet_probe)
