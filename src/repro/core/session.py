@@ -787,12 +787,14 @@ class TinyTrainSession:
                         sup, pq = (host_ingest(idxs, False) if hosted
                                    else stacked(idxs))
                     with telemetry.span("adapt_many.probe.run") as run_sp:
-                        chans_dev = jax.block_until_ready(
+                        packed = jax.block_until_ready(
                             self.step_cache.probe_fisher_batch()(
                                 params_run, sup, pq))
+                    # the group's scores arrive packed: one array, one copy
                     with telemetry.span(
                             "adapt_many.probe.fetch") as fetch_sp:
-                        chans_all = fetch(chans_dev)
+                        chans_all = self.step_cache.probe_layout().unpack(
+                            fetch(packed))
                     dt = (run_sp.seconds + fetch_sp.seconds) / len(idxs)
                     with telemetry.span("adapt_many.select"):
                         for j, i in enumerate(idxs):

@@ -14,10 +14,13 @@ the eager steps report the loss as NaN.
 """
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..optim import Optimizer, apply_updates
 from ..utils import tree_size
@@ -181,6 +184,54 @@ def make_episode_sparse_scan(
     return jax.jit(run, donate_argnums=(1, 2))
 
 
+@dataclasses.dataclass(frozen=True)
+class ScoreLayout:
+    """Where each unit's Fisher scores sit in the fleet probe's packed array.
+
+    Unit ``keys[i]``'s per-task scores, shaped ``shapes[i]``, fill columns
+    ``offsets[i]:offsets[i + 1]`` of one ``(T, sum C)`` array, so a probe
+    group's scores reach the host in one copy instead of one per unit.
+    """
+    keys: Tuple[Any, ...]
+    shapes: Tuple[Tuple[int, ...], ...]
+    offsets: Tuple[int, ...]
+
+    @classmethod
+    def of(cls, scores: Dict) -> "ScoreLayout":
+        """The layout of task-stacked scores ``{key: (T, *shape)}``, from
+        their shapes alone (arrays, tracers or shape structs).  All leaves
+        must share one dtype: packing casts nothing."""
+        keys = tuple(sorted(scores))
+        dtypes = {jnp.dtype(scores[k].dtype) for k in keys}
+        if len(dtypes) != 1:
+            raise TypeError(
+                f"Fisher scores mix dtypes {sorted(map(str, dtypes))}; "
+                "packing them would cast")
+        shapes = tuple(tuple(scores[k].shape[1:]) for k in keys)
+        offsets = [0]
+        for s in shapes:
+            offsets.append(offsets[-1] + math.prod(s))
+        return cls(keys, shapes, tuple(offsets))
+
+    def pack(self, scores: Dict) -> jax.Array:
+        """Inside the program: every unit's scores side by side along the
+        channel axis, ``(T, sum C)``; the task axis stays leading."""
+        t = scores[self.keys[0]].shape[0]
+        return jnp.concatenate(
+            [scores[k].reshape(t, -1) for k in self.keys], axis=1)
+
+    def unpack(self, packed: np.ndarray) -> Dict:
+        """On the host: ``{key: (T, *shape)}`` numpy views into ``packed``
+        (no copies), the dict the unpacked probe would have returned."""
+        t, width = packed.shape
+        if width != self.offsets[-1]:
+            raise ValueError(f"packed scores have {width} columns; the "
+                             f"layout has {self.offsets[-1]}")
+        return {k: packed[:, lo:hi].reshape((t,) + s)
+                for k, s, lo, hi in zip(self.keys, self.shapes,
+                                        self.offsets, self.offsets[1:])}
+
+
 class EpisodeStepCache:
     """Adaptation-engine jit cache: one compile per policy *structure*.
 
@@ -201,6 +252,7 @@ class EpisodeStepCache:
         self._probe = None
         self._probe_fisher = None
         self._probe_fisher_batch = None
+        self._probe_layout = None
 
     def probe_grad(self):
         """Jitted Fisher-probe gradient, compiled once per backbone (episodes
@@ -273,6 +325,11 @@ class EpisodeStepCache:
         stacked row count (constants, broadcast) and each task's valid
         count ``n``, its support labels >= 0 (``Task.n_support``; padded
         and repeated rows count as their task does).
+
+        It returns one ``(T, sum C)`` array: every unit's per-channel
+        scores packed along the channel axis inside the program, so the
+        host copies a group's scores once.  :meth:`probe_layout` says
+        which columns are whose.
         """
         if self._probe_fisher_batch is None:
             batched = jax.vmap(self._probe_fisher_fn(),
@@ -284,10 +341,22 @@ class EpisodeStepCache:
                 labels = supports["episode_labels"]
                 taps = backbone.make_taps(labels.shape[1])
                 ns = jnp.sum(labels >= 0, axis=1).astype(jnp.float32)
-                return batched(params, supports, queries, taps, ns)
+                scores = batched(params, supports, queries, taps, ns)
+                # the reduction's output shapes are static: kept while
+                # the program traces, the same for every group shape
+                self._probe_layout = ScoreLayout.of(scores)
+                return self._probe_layout.pack(scores)
 
             self._probe_fisher_batch = jax.jit(fleet_probe)
         return self._probe_fisher_batch
+
+    def probe_layout(self) -> ScoreLayout:
+        """The column layout of :meth:`probe_fisher_batch`'s output, found
+        from the reduction's output shapes when the program first traced
+        (so after its first call)."""
+        if self._probe_layout is None:
+            raise RuntimeError("the fleet probe has not traced yet")
+        return self._probe_layout
 
     @staticmethod
     def _key(policy: SparseUpdatePolicy):

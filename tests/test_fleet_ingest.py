@@ -9,6 +9,9 @@ in a bounded number of compiled programs.
   another order builds nothing new.
 - The fleet probe, fed only the stacked episodes, scores every task as the
   per-task probe does with explicit taps and ``Task.n_support``.
+- The fleet probe packs a group's scores into one array, so the group's
+  fetch copies one array, and every task picks the channels the
+  sequential probe picks.
 """
 import dataclasses
 
@@ -16,7 +19,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro import api, data, telemetry
+from repro import api, configs, data, telemetry
 from repro.core import adapt as adapt_mod
 from repro.core import session as S
 from repro.core.backbones import cnn_backbone, lm_backbone
@@ -37,6 +40,14 @@ def _micro_lm():
                      vocab=64, n_heads=2, n_kv_heads=2, head_dim=16,
                      d_ff=64, dtype="float32").validate()
     return lm_backbone(cfg, tokens_per_batch=32, batch_size=2)
+
+
+def _wide_lm():
+    """An LM whose unit kinds differ in width (4 MLA heads, a 128-wide
+    dense MLP, 8 MoE experts), so every offset of the packed scores
+    counts."""
+    return lm_backbone(configs.get_reduced("deepseek-v3-671b"),
+                       tokens_per_batch=32, batch_size=2)
 
 
 def _cnn_tasks(rng, combos, n):
@@ -175,16 +186,20 @@ def _probe_cases():
                 _cnn_tasks(rng, [(3, 2)], 3)),
         "lm": (_micro_lm, _lm_tasks(rng, [(2, 2), (3, 2), (2, 3)], 3),
                _lm_tasks(rng, [(2, 2)], 3)),
+        "lm-widths": (_wide_lm, _lm_tasks(rng, [(2, 2), (3, 2), (2, 3)], 3,
+                                          vocab=512),
+                      _lm_tasks(rng, [(2, 2)], 3, vocab=512)),
     }
 
 
 class TestFleetProbeMatchesExplicitTaps:
     @pytest.mark.parametrize("bucket", [True, False])
-    @pytest.mark.parametrize("kind", ["cnn", "lm"])
+    @pytest.mark.parametrize("kind", ["cnn", "lm", "lm-widths"])
     def test_scores_match_per_task_probe(self, kind, bucket):
         """Each group's fleet probe (taps and valid counts made inside the
-        program) == the per-task probe given ``make_taps(batch_pad)`` and
-        ``Task.n_support`` explicitly, bucketed and exact-shape."""
+        program), unpacked through its layout, == the per-task probe given
+        ``make_taps(batch_pad)`` and ``Task.n_support`` explicitly,
+        bucketed and exact-shape."""
         make_bb, mixed, same = _probe_cases()[kind]
         session = api.TinyTrainSession(make_bb(), max_way=4, seed=0)
         bb, cache = session.backbone, session.step_cache
@@ -197,10 +212,57 @@ class TestFleetProbeMatchesExplicitTaps:
         for idxs in groups.values():
             sup, pq = S._fleet_stack_episodes([eps[i][0] for i in idxs],
                                               [eps[i][1] for i in idxs])
-            got = cache.probe_fisher_batch()(session.params, sup, pq)
+            packed = cache.probe_fisher_batch()(session.params, sup, pq)
+            layout = cache.probe_layout()
+            assert packed.shape == (len(idxs), layout.offsets[-1])
+            if kind == "lm-widths":
+                assert len(set(layout.shapes)) == 3
+            got = layout.unpack(np.asarray(packed))
             for j, i in enumerate(idxs):
                 rows = int(eps[i][0]["episode_labels"].shape[0])
                 want = cache.probe_fisher()(
                     session.params, eps[i][0], eps[i][1],
                     bb.make_taps(rows), np.float32(tasks[i].n_support))
                 _assert_trees_close({k: v[j] for k, v in got.items()}, want)
+
+
+class TestProbeGroupFetchesOneArray:
+    def test_one_array_per_probe_group_and_sequential_picks(self):
+        """A probe group's fetch copies one packed array: the root's
+        ``arrays_fetched`` is the probe groups plus each run group's
+        deltas, losses and skips; ``host_syncs`` stays one per group.  The
+        unpacked scores pick, for every task, the units and channels of
+        the sequential probe-and-select path."""
+        session = api.TinyTrainSession(_micro_cnn(), max_way=4, seed=0)
+        rng = np.random.default_rng(7)
+        tasks = _cnn_tasks(rng, MIX, 8)
+        syncs0 = adapt_mod.host_sync_count()
+        out = session.adapt_many(tasks, api.RPI_ZERO, iters=2)
+        syncs = adapt_mod.host_sync_count() - syncs0
+        root = [r for r in telemetry.RECORDER.records
+                if r.name == "adapt_many"][-1]
+        c = root.counts
+        run_groups = {}
+        for t, a in zip(tasks, out):
+            key = (S._episode_shape_key(*S._bucket_episode(t)),
+                   session.step_cache._key(a.policy))
+            run_groups.setdefault(key, a)
+        assert c["finetune_groups"] == len(run_groups)
+        finetune_leaves = sum(len(jax.tree_util.tree_leaves(a.deltas)) + 2
+                              for a in run_groups.values())
+        assert c["arrays_fetched"] == c["probe_groups"] + finetune_leaves
+        assert syncs == c["host_syncs"] == (c["probe_groups"]
+                                            + c["finetune_groups"])
+        budget = S._as_budget(api.RPI_ZERO)
+        for t, a in zip(tasks, out):
+            want, _, _ = adapt_mod._probe_and_select(
+                session.backbone, session.params, t.support, t.pseudo_query,
+                budget, max_way=4, criterion="tinytrain", shard_channels=1,
+                step_cache=session.step_cache)
+            assert a.policy.units == want.units
+            assert a.policy.channel_idx.keys() == want.channel_idx.keys()
+            for lid, kinds in want.channel_idx.items():
+                assert a.policy.channel_idx[lid].keys() == kinds.keys()
+                for k, idx in kinds.items():
+                    np.testing.assert_array_equal(
+                        a.policy.channel_idx[lid][k], idx)

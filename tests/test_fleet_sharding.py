@@ -74,26 +74,76 @@ def _run_mesh_parity():
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _run_padded_group_parity():
+    """Probe groups whose task count the 8-way mesh pads: the mesh path
+    and per-host ingestion (2 hosts) drop the padded rows of each group's
+    packed scores and pick every task's units and channels as the single
+    device does, each probe group fetching one array."""
+    from repro import telemetry
+    from repro.core import session as S
+
+    session = _micro_session()
+    rng = np.random.default_rng(3)
+    tasks = _het_tasks(rng, [(2, 2), (4, 3)], 6)
+    mesh = make_mesh((8,), ("data",))
+    rules = FleetShardingRules(mesh)
+    keys = [S._episode_shape_key(*S._bucket_episode(t)) for t in tasks]
+    groups = S._group_indices(keys)
+    assert all(rules.padded_count(len(g)) != len(g)
+               for g in groups.values())
+    fleet_1 = session.adapt_many(tasks, api.RPI_ZERO, iters=2)
+    for kw in (dict(mesh=mesh), dict(mesh=mesh, hosts=2)):
+        fleet = session.adapt_many(tasks, api.RPI_ZERO, iters=2, **kw)
+        root = [r for r in telemetry.RECORDER.records
+                if r.name == "adapt_many"][-1]
+        c = root.counts
+        assert c["probe_groups"] == len(groups)
+        assert c["host_syncs"] == c["probe_groups"] + c["finetune_groups"]
+        run_groups = {(k, session.step_cache._key(a.policy)): a
+                      for k, a in zip(keys, fleet)}
+        assert c["finetune_groups"] == len(run_groups)
+        # one packed array per probe group; deltas, losses and skips per
+        # run group
+        assert c["arrays_fetched"] == c["probe_groups"] + sum(
+            len(jax.tree_util.tree_leaves(a.deltas)) + 2
+            for a in run_groups.values())
+        for a, s in zip(fleet, fleet_1):
+            assert a.policy.units == s.policy.units
+            for lid, kinds in s.policy.channel_idx.items():
+                for k, idx in kinds.items():
+                    np.testing.assert_array_equal(
+                        a.policy.channel_idx[lid][k], idx)
+
+
+def _in_8_devices(body):
+    """Run ``body`` (a function of this module) with 8 host-platform
+    devices: here when the process has them, else in a subprocess under
+    the device-count flag."""
+    if jax.device_count() >= 8:
+        body()
+        return
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = (
+        os.path.join(_REPO, "src") + os.pathsep + _REPO
+        + os.pathsep + env.get("PYTHONPATH", ""))
+    code = ("import tests.test_fleet_sharding as t; "
+            f"t.{body.__name__}(); print('MESH_PARITY_OK')")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], env=env, cwd=_REPO,
+        capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "MESH_PARITY_OK" in proc.stdout
+
+
 class TestMeshParity:
     def test_adapt_many_mesh_matches_single_device(self):
-        if jax.device_count() >= 8:
-            _run_mesh_parity()
-            return
-        # re-run this module's parity body under the 8-device flag
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8")
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = (
-            os.path.join(_REPO, "src") + os.pathsep + _REPO
-            + os.pathsep + env.get("PYTHONPATH", ""))
-        code = ("import tests.test_fleet_sharding as t; "
-                "t._run_mesh_parity(); print('MESH_PARITY_OK')")
-        proc = subprocess.run(
-            [sys.executable, "-c", code], env=env, cwd=_REPO,
-            capture_output=True, text=True, timeout=900)
-        assert proc.returncode == 0, proc.stderr[-4000:]
-        assert "MESH_PARITY_OK" in proc.stdout
+        _in_8_devices(_run_mesh_parity)
+
+    def test_padded_probe_groups_pick_single_device_policies(self):
+        _in_8_devices(_run_padded_group_parity)
 
 
 class TestCompileBudget:
